@@ -31,6 +31,7 @@ tokens the uninterrupted run would have produced:
         --journal-dir /tmp/serve-crash --resume
 """
 import argparse
+import sys
 import time
 
 import jax
@@ -38,6 +39,7 @@ import numpy as np
 
 from repro import configs
 from repro.models import lm
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.serve.engine import Engine
 
 
@@ -59,6 +61,7 @@ def main() -> None:
                     help="recover and finish journaled requests")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = configs.get_smoke(args.arch)
     print(f"serving {cfg.name} ({cfg.param_count()/1e6:.1f}M params, "
           f"reduced config)")
@@ -104,6 +107,8 @@ def main() -> None:
     print(f"engine stats: {stats}")
     print(f"health: {health}")
     print(f"scheduler: {engine.scheduler_report()}")
+    if stats["failed"]:
+        sys.exit(1)
 
 
 if __name__ == "__main__":

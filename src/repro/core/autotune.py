@@ -64,7 +64,8 @@ now charges packed-plane + outlier-sidecar bytes for weight traffic,
 so v5 GEMM/conv rankings are stale and every v5 entry is orphaned.
 
 An optional *empirical refinement* pass (``refine=True``) re-ranks the
-analytical top-k by interpret-mode wall clock before caching, trading
+analytical top-k by wall clock on the backend being tuned (the Pallas
+interpreter only for ``backend="interpret"``) before caching, trading
 one-off tuning time for a measured winner — the PolyDL observation that
 autotuned selection over a pruned space beats a purely analytical pick.
 The re-rank runs through the registration's ``measure`` hook, so every
@@ -296,7 +297,7 @@ def refine_enabled() -> bool:
 
 def best_spec(
     problem: Problem,
-    hw: cost_model.HardwareSpec = cost_model.V5E,
+    hw: Optional[cost_model.HardwareSpec] = None,
     backend: str = "pallas",
     refine: Optional[bool] = None,
     refine_top: int = 3,
@@ -312,8 +313,12 @@ def best_spec(
     in packed words, attention ``(bq, bkv, d)``).  ``refine=None``
     defers to the ``REPRO_AUTOTUNE_REFINE=1`` env flag (default off);
     the re-rank runs the registration's ``measure`` hook on the
-    analytical top-k.
+    analytical top-k, timed on the backend being tuned (interpret mode
+    only when ``backend == "interpret"``).  ``hw=None`` takes the
+    device's model (``cost_model.hardware_for``).
     """
+    if hw is None:
+        hw = cost_model.hardware_for()
     if refine is None:
         refine = refine_enabled()
     _load_disk()
@@ -332,7 +337,7 @@ def best_spec(
     spec = ranked[0].spec
     if refine and reg.measure is not None and len(ranked) > 1:
         measured = reg.measure(problem, [c.spec for c in ranked],
-                               interpret=True)
+                               interpret=backend == "interpret")
         spec = measured[0][0]
     _memory[key] = spec
     if not _defer_save:
@@ -342,7 +347,7 @@ def best_spec(
 
 def warm(
     problems: Iterable[Problem],
-    hw: cost_model.HardwareSpec = cost_model.V5E,
+    hw: Optional[cost_model.HardwareSpec] = None,
     backend: str = "pallas",
 ) -> List[DataflowSpec]:
     """Pre-populate the cache for a known set of hot workloads (any
@@ -355,6 +360,8 @@ def warm(
     than aborting the warm-up — the op will raise at call time instead.
     """
     global _defer_save
+    if hw is None:
+        hw = cost_model.hardware_for()
     before = _stats["misses"]
     _defer_save = True
     specs = []

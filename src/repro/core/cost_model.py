@@ -83,6 +83,32 @@ class HardwareSpec:
 
 V5E = HardwareSpec()
 
+# Per-chip peaks keyed by ``jax.Device.device_kind``.  Source: Google
+# Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB of HBM at
+# 819 GB/s per chip.  A TPU whose kind is missing here is an error,
+# never a silent V5E.
+HARDWARE_BY_KIND: Dict[str, HardwareSpec] = {"TPU v5 lite": V5E}
+
+
+def hardware_for(device=None) -> HardwareSpec:
+    """The hardware model of ``device`` (default ``jax.devices()[0]``).
+
+    On a TPU it is the ``HARDWARE_BY_KIND`` entry for the device's kind,
+    and an unknown kind raises ``ValueError``.  Off the chip (CPU tests,
+    interpret mode) the explorer's modelled target is ``V5E``.
+    """
+    import jax
+
+    device = device if device is not None else jax.devices()[0]
+    if device.platform != "tpu":
+        return V5E
+    try:
+        return HARDWARE_BY_KIND[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no hardware model for TPU kind {device.device_kind!r}; "
+            f"known kinds: {sorted(HARDWARE_BY_KIND)}") from None
+
 
 # ---------------------------------------------------------------------------
 # 1. Paper Table I, literal CPU/SIMD form.

@@ -3,7 +3,7 @@ from __future__ import annotations
 
 
 def _subjaxprs(v):
-    from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     if isinstance(v, ClosedJaxpr):
         yield v.jaxpr

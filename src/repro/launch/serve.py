@@ -25,12 +25,14 @@ bit-identical greedy tokens:
 from __future__ import annotations
 
 import argparse
+import sys
 
 import jax
 import numpy as np
 
 from repro import configs
 from repro.models import lm
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.serve.engine import Engine
 
 
@@ -56,6 +58,7 @@ def main() -> None:
                          "finish serving them")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = configs.get_smoke(args.arch) if args.smoke else configs.get(
         args.arch)
     params = lm.init_model(cfg, jax.random.PRNGKey(args.seed))
@@ -88,7 +91,10 @@ def main() -> None:
           f"degraded_steps={stats['degraded_steps']} "
           f"snapshots={stats['snapshots_saved']} "
           f"recovered={stats['recovered']} "
-          f"replayed_steps={stats['replayed_steps']}")
+          f"replayed_steps={stats['replayed_steps']} "
+          f"failed={stats['failed']}")
+    if stats["failed"]:
+        sys.exit(1)
 
 
 if __name__ == "__main__":

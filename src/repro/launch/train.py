@@ -15,6 +15,7 @@ import argparse
 import jax
 
 from repro import configs
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.runtime.driver import TrainDriver, TrainJobConfig
 
 
@@ -37,6 +38,7 @@ def main() -> None:
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = configs.get_smoke(args.arch) if args.smoke else configs.get(
         args.arch)
     job = TrainJobConfig(

@@ -25,23 +25,6 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-try:  # jax >= 0.5 exports shard_map at top level
-    _shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover - version-dependent
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def _shard_map(f, **kw):
-        # the experimental replication checker has known false positives
-        # (e.g. on scan carries); newer jax removed the knob entirely
-        kw.setdefault("check_rep", False)
-        return _exp_shard_map(f, **kw)
-
-
-def _axis_size(axis_name: str) -> int:
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)  # pre-0.5 spelling
-
 from repro.models import layers
 
 Params = Dict[str, jax.Array]
@@ -156,7 +139,7 @@ def moe_apply(
     x_flat = x.reshape(-1, d)
     t = x_flat.shape[0]
     e, k = cfg.n_experts, cfg.top_k
-    ep = _axis_size(ep_axis) if ep_axis else 1
+    ep = jax.lax.axis_size(ep_axis) if ep_axis else 1
     e_loc = e // ep
 
     top_g, top_e, aux = _route(x_flat, p["router"], k)
@@ -206,7 +189,7 @@ def moe_apply_psum_local(
     x_flat = x.reshape(-1, d)
     t = x_flat.shape[0]
     e, k = cfg.n_experts, cfg.top_k
-    ep = _axis_size(ep_axis)
+    ep = jax.lax.axis_size(ep_axis)
     e_loc = e // ep
     rank = jax.lax.axis_index(ep_axis)
 
@@ -272,7 +255,7 @@ def moe_apply_sharded(
         aux = jax.lax.pmean(jax.lax.pmean(aux, tp_axis), dp_axes)
         return y, aux
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh, in_specs=in_specs,
         out_specs=(pspec_x, P()),
     )

@@ -256,16 +256,18 @@ class Engine:
     as a deprecated prompts-in/tokens-out shim over submit + drain.
 
     ``hw`` is the admission-control hardware model (VMEM feasibility of
-    the decode-step attention); tests pass a tiny ``HardwareSpec`` to
-    force rejections.  ``policy``/``monitor`` own degradation state and
-    the health ledger; callers may share one monitor across engines.
+    the decode-step attention), by default the device's
+    (``cost_model.hardware_for``: an unknown TPU kind raises); tests
+    pass a tiny ``HardwareSpec`` to force rejections.
+    ``policy``/``monitor`` own degradation state and the health ledger;
+    callers may share one monitor across engines.
     """
 
     def __init__(self, cfg, params, max_len: int = 2048,
                  dist: Optional[lm.Dist] = None,
                  monitor: Optional[health.HealthMonitor] = None,
                  policy: Optional[health.DegradationPolicy] = None,
-                 hw: cost_model.HardwareSpec = cost_model.V5E,
+                 hw: Optional[cost_model.HardwareSpec] = None,
                  validate_outputs: bool = True,
                  journal_dir: Optional[str] = None,
                  snapshot_dir: Optional[str] = None,
@@ -275,7 +277,7 @@ class Engine:
         self.params = params
         self.max_len = max_len
         self.dist = dist
-        self.hw = hw
+        self.hw = hw if hw is not None else cost_model.hardware_for()
         self.validate_outputs = validate_outputs
         self.monitor = monitor if monitor is not None else health.HealthMonitor()
         self.policy = policy if policy is not None else health.DegradationPolicy()
@@ -482,8 +484,10 @@ class Engine:
                 logits, cache = fn()
                 if fault == "nan":
                     logits = logits * jnp.asarray(jnp.nan, logits.dtype)
-                if self.validate_outputs and not bool(
-                        jnp.all(jnp.isfinite(logits))):
+                # the model masks the padding rows past vocab_size to
+                # -inf: only the real vocabulary must be finite
+                if self.validate_outputs and not bool(jnp.all(jnp.isfinite(
+                        logits[..., :self.cfg.vocab_size]))):
                     raise NonFiniteLogits(
                         f"non-finite logits from {site} step {step} "
                         f"({path} path)")
@@ -491,7 +495,10 @@ class Engine:
             except Exception as e:
                 # SimulatedFailure, NonFiniteLogits, kernel lowering /
                 # interpret errors — anything a bad step can surface.
-                failure = e
+                # Kept without its traceback: the frames would hold the
+                # step's device arguments (parameters, page pools) in a
+                # reference cycle until the next garbage collection.
+                failure = e.with_traceback(None)
             self.policy.on_failure(site, step, failure, self.monitor)
             self._counters["demotions"] += 1
             attempt += 1

@@ -299,9 +299,11 @@ def test_refine_measure_hook_runs_for_conv_and_binary(monkeypatch):
     """REPRO_AUTOTUNE_REFINE=1 re-ranks conv and binary misses through
     the registration's measure hook (GEMM-only before PR 4)."""
     calls = []
+    modes = []
 
     def spy(problem, specs, interpret=True):
         calls.append(type(problem).__name__)
+        modes.append(interpret)
         return [(s, float(i)) for i, s in enumerate(specs)]
 
     monkeypatch.setattr(explorer, "_measure_conv", spy)
@@ -316,6 +318,10 @@ def test_refine_measure_hook_runs_for_conv_and_binary(monkeypatch):
     # cached: the hook does not rerun on hits
     autotune.best_spec(CONV_PROBLEM, backend="interpret")
     assert len(calls) == 3
+    # the re-rank times the backend being tuned: interpret mode only
+    # for backend="interpret", the compiled kernels otherwise
+    autotune.best_spec(ATTN_PROBLEM, backend="pallas")
+    assert modes == [True, True, True, False]
     autotune.clear(disk=True)
 
 

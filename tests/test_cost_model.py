@@ -114,3 +114,22 @@ def test_roofline_terms_and_dominance():
 def test_model_flops():
     assert cost_model.model_flops(1e9, 1e6) == 6e15
     assert cost_model.model_flops(1e9, 1e6, training=False) == 2e15
+
+
+def test_hardware_model_follows_the_device_kind():
+    """On a TPU the hardware model comes from the device's kind, and an
+    unknown kind raises instead of defaulting to v5e; off the chip the
+    explorer models v5e."""
+    import types
+
+    import pytest
+
+    def device(platform, kind):
+        return types.SimpleNamespace(platform=platform, device_kind=kind)
+
+    assert cost_model.hardware_for(device("tpu", "TPU v5 lite")) \
+        is cost_model.V5E
+    assert cost_model.hardware_for(device("cpu", "cpu")) is cost_model.V5E
+    assert cost_model.hardware_for() is cost_model.V5E   # CPU test run
+    with pytest.raises(ValueError, match="TPU v99"):
+        cost_model.hardware_for(device("tpu", "TPU v99"))

@@ -8,6 +8,7 @@ exhaustion, and autotune-cache corruption recovery.
 
 CI runs this file as the ``fault-drill`` job."""
 import dataclasses
+import gc
 import glob
 import json
 import os
@@ -126,6 +127,50 @@ def test_retries_exhausted_marks_requests_failed(served):
     assert all("injected failure" in r.error for r in reqs)
     st = eng.stats()
     assert st["failed"] == 2 and st["retries"] == 2
+
+
+def test_failed_steps_release_their_arguments(served):
+    """A failed step's arguments are freed with the step, not kept by
+    the error's traceback until a garbage collection: on the chip those
+    frames held whole page pools, and failing decode steps ran the
+    device out of memory."""
+    params, prompts = served
+    os.environ["REPRO_FAULT_PLAN"] = "serve.decode_step:*:raise"
+    health.reset_faults()
+    eng = Engine(CFG, params, max_len=MAX_LEN,
+                 policy=health.DegradationPolicy(backoff_base_s=0.001))
+    eng.drain()                          # builds the scheduler and pools
+    reqs = [eng.submit(p, NEW_TOKENS) for p in prompts]
+    gc.disable()
+    try:
+        before = len(jax.live_arrays())
+        eng.drain()
+        after = len(jax.live_arrays())
+    finally:
+        gc.enable()
+    assert all(r.state == RequestState.FAILED for r in reqs)
+    assert eng.stats()["demotions"] > 0
+    assert after <= before
+
+
+@pytest.mark.parametrize("loop", ["serve", "drain"])
+def test_padded_vocab_mask_is_not_a_fault(loop):
+    """The decode step masks the embedding's padding rows past
+    ``vocab_size`` to -inf; the step validator checks only the real
+    vocabulary, so such a config serves with no demotion (qwen3-1.7b's
+    151936 rows pad to 152064)."""
+    cfg = dataclasses.replace(CFG, vocab_size=500)
+    assert cfg.padded_vocab > cfg.vocab_size
+    params = lm.init_model(cfg, jax.random.PRNGKey(0))
+    prompts = np.random.default_rng(0).integers(
+        0, cfg.vocab_size, (2, 8)).astype(np.int32)
+    eng = Engine(cfg, params, max_len=MAX_LEN)
+    reqs = [eng.submit(p, NEW_TOKENS) for p in prompts]
+    eng.serve(reqs) if loop == "serve" else eng.drain()
+    assert all(r.state == RequestState.DONE for r in reqs)
+    assert all(0 <= t < cfg.vocab_size for r in reqs for t in r.out_tokens)
+    st = eng.stats()
+    assert st["demotions"] == st["retries"] == st["degraded_steps"] == 0
 
 
 def test_generate_raises_on_failed_batch(served):

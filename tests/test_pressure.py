@@ -238,7 +238,7 @@ def test_pool_alloc_fault_site_is_a_simulated_oom(monkeypatch):
 # ---------------------------------------------------------------------------
 # Property: the pool conserves pages under any op sequence.
 # ---------------------------------------------------------------------------
-@settings(max_examples=15)
+@settings(max_examples=15, deadline=None)
 @given(st.lists(st.integers(0, 3), min_size=1, max_size=50))
 def test_pool_conserves_pages_under_any_op_sequence(ops):
     import jax.numpy as jnp
