@@ -34,6 +34,7 @@ def main(argv=None) -> None:
         bench_fused,
         bench_heuristics,
         bench_roofline,
+        bench_trace,
     )
 
     suites = [
@@ -47,6 +48,7 @@ def main(argv=None) -> None:
         ("fused_epilogue", bench_fused.run),
         ("fused_conv", bench_conv.run),
         ("roofline", bench_roofline.run),
+        ("trace_spans", bench_trace.run),
     ]
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument(

@@ -39,11 +39,12 @@ unlike the per-process hit counter).
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import os
 import signal
 import time
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Deque, Dict, List, Optional, Tuple
 
 
 class SimulatedFailure(RuntimeError):
@@ -216,10 +217,13 @@ class HealthMonitor:
     """Per-host step timing + straggler detection + event ledger.
 
     A step is flagged a straggler when it exceeds ``threshold`` x the
-    rolling median of the last ``window`` steps.  At cluster scale the
-    same statistic over per-host heartbeats identifies slow hosts; the
-    mitigation hook is pluggable (default: record + warn — a production
-    deployment plugs in hot-spare promotion or in-flight re-dispatch).
+    rolling median of the last ``window`` steps.  The monitor keeps only
+    those steps and the last ``window`` stragglers, with exact counts of
+    both, so its memory does not grow with the steps it has seen.  At
+    cluster scale the same statistic over per-host heartbeats identifies
+    slow hosts; the mitigation hook is pluggable (default: record + warn
+    — a production deployment plugs in hot-spare promotion or in-flight
+    re-dispatch).
 
     Beyond timing, the monitor is the single *ledger* for the serving
     stack: kernel demotions, retries, Pallas re-probes, admission
@@ -231,19 +235,24 @@ class HealthMonitor:
                  on_straggler: Optional[Callable[[StepRecord], None]] = None):
         self.window = window
         self.threshold = threshold
-        self.records: List[StepRecord] = []
-        self.stragglers: List[StepRecord] = []
+        self.records: Deque[StepRecord] = collections.deque(maxlen=window)
+        self.stragglers: List[StepRecord] = []     # the last ``window``
+        self.steps = 0
+        self.straggler_count = 0
         self.on_straggler = on_straggler
         self.events: List[HealthEvent] = []
 
     def record(self, step: int, seconds: float) -> bool:
         rec = StepRecord(step, seconds, time.time())
-        recent = [r.seconds for r in self.records[-self.window:]]
+        recent = [r.seconds for r in self.records]
         self.records.append(rec)
+        self.steps += 1
         if len(recent) >= 8:
             med = sorted(recent)[len(recent) // 2]
             if seconds > self.threshold * med:
+                self.straggler_count += 1
                 self.stragglers.append(rec)
+                del self.stragglers[:-self.window]
                 self.note("straggler", step=step,
                           detail=f"{seconds:.3f}s vs median {med:.3f}s")
                 if self.on_straggler:
@@ -262,6 +271,7 @@ class HealthMonitor:
 
     @property
     def median_step_seconds(self) -> float:
+        """The median of the last ``window`` steps."""
         if not self.records:
             return 0.0
         xs = sorted(r.seconds for r in self.records)
@@ -274,9 +284,9 @@ class HealthMonitor:
         for e in self.events:
             by_kind[e.kind] = by_kind.get(e.kind, 0) + 1
         return {
-            "steps": len(self.records),
+            "steps": self.steps,
             "median_step_seconds": self.median_step_seconds,
-            "stragglers": len(self.stragglers),
+            "stragglers": self.straggler_count,
             "events": by_kind,
             "injected_faults": [
                 (f.site, f.hit, f.kind) for f in fault_log()

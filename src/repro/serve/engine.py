@@ -118,7 +118,7 @@ import numpy as np
 from repro.ckpt.checkpoint import Checkpointer, CheckpointError
 from repro.core import autotune, cost_model, explorer
 from repro.models import layers, lm
-from repro.runtime import elastic, health
+from repro.runtime import elastic, health, trace
 from repro.serve import journal as journal_lib
 from repro.serve.paged_cache import pages_for
 from repro.serve.scheduler import (ContinuousScheduler, SamplingParams,
@@ -200,6 +200,8 @@ class Request:
     degraded_steps: int = 0       # decode steps served on the XLA path
     queue_reason: Optional[str] = None   # why a QUEUED request is waiting
     #                                      (watermark / pool backpressure)
+    admitted_s: Optional[float] = None   # monotonic time of its first
+    #                                      admission to a batch row
 
 
 @dataclasses.dataclass
@@ -330,7 +332,7 @@ class Engine:
             "recovered": 0, "replayed_steps": 0,
             "replay_divergence": 0, "restore_fallbacks": 0,
             "spills": 0, "spilled_pages": 0, "unspills": 0,
-            "preemptions": 0, "backpressure": 0,
+            "preemptions": 0, "backpressure": 0, "ticks": 0,
         }
 
     # ------------------------------------------------------------------
@@ -353,10 +355,12 @@ class Engine:
         if key in self._admission_cache:
             return self._admission_cache[key]
         ok = True
-        for p in lm.hot_attention_problems(self.cfg, 1, max(seq, 1), cap):
-            if not explorer.enumerate_attention_candidates(p, self.hw):
-                ok = False
-                break
+        with trace.span("serve.admission_check", seq=seq, reach=cap):
+            for p in lm.hot_attention_problems(self.cfg, 1, max(seq, 1),
+                                               cap):
+                if not explorer.enumerate_attention_candidates(p, self.hw):
+                    ok = False
+                    break
         self._admission_cache[key] = ok
         return ok
 
@@ -384,6 +388,14 @@ class Engine:
         cannot fit the hardware's VMEM under any dataflow at the
         request's KV reach (``prompt + budget``, clamped to capacity).
         """
+        with trace.span("serve.submit") as sp:
+            req = self._submit(prompt, max_new_tokens, deadline_s, sampling)
+            sp.set(rid=req.rid)
+        return req
+
+    def _submit(self, prompt, max_new_tokens: Optional[int],
+                deadline_s: Optional[float],
+                sampling: Optional[SamplingParams]) -> RequestHandle:
         self._counters["submitted"] += 1
         if max_new_tokens is None:
             max_new_tokens = (sampling.max_new_tokens if sampling
@@ -484,13 +496,18 @@ class Engine:
                 logits, cache = fn()
                 if fault == "nan":
                     logits = logits * jnp.asarray(jnp.nan, logits.dtype)
+                with trace.span("serve.wait"):
+                    jax.block_until_ready(logits)
                 # the model masks the padding rows past vocab_size to
                 # -inf: only the real vocabulary must be finite
-                if self.validate_outputs and not bool(jnp.all(jnp.isfinite(
-                        logits[..., :self.cfg.vocab_size]))):
-                    raise NonFiniteLogits(
-                        f"non-finite logits from {site} step {step} "
-                        f"({path} path)")
+                if self.validate_outputs:
+                    with trace.span("serve.validate"):
+                        finite = bool(jnp.all(jnp.isfinite(
+                            logits[..., :self.cfg.vocab_size])))
+                    if not finite:
+                        raise NonFiniteLogits(
+                            f"non-finite logits from {site} step {step} "
+                            f"({path} path)")
                 return logits, cache, path
             except Exception as e:
                 # SimulatedFailure, NonFiniteLogits, kernel lowering /
@@ -539,13 +556,14 @@ class Engine:
         if key in self._warmed:
             return
         self._warmed.add(key)
-        autotune.warm(lm.hot_gemm_problems(self.cfg, batch, seq)
-                      + lm.hot_gemm_problems(self.cfg, batch, 1)
-                      + lm.hot_attention_problems(self.cfg, batch, seq,
-                                                  self.max_len)
-                      + lm.hot_conv_problems(self.cfg, batch, seq)
-                      + lm.hot_binary_problems(self.cfg, batch, seq)
-                      + lm.hot_binary_problems(self.cfg, batch, 1))
+        with trace.span("serve.autotune_warm", seq=seq):
+            autotune.warm(lm.hot_gemm_problems(self.cfg, batch, seq)
+                          + lm.hot_gemm_problems(self.cfg, batch, 1)
+                          + lm.hot_attention_problems(self.cfg, batch, seq,
+                                                      self.max_len)
+                          + lm.hot_conv_problems(self.cfg, batch, seq)
+                          + lm.hot_binary_problems(self.cfg, batch, seq)
+                          + lm.hot_binary_problems(self.cfg, batch, 1))
 
     def serve(self, requests: Sequence[Request], greedy: bool = True,
               seed: int = 0) -> List[Request]:
@@ -602,6 +620,8 @@ class Engine:
 
         for r in reqs:
             r.state = RequestState.PREFILLING
+            if r.admitted_s is None:
+                r.admitted_s = t_start
         dev_prompts = jnp.asarray(prompts)
         try:
             logits, cache, path = self._execute(
@@ -679,10 +699,12 @@ class Engine:
         request (or push one prefill chunk), then run one decode step
         over every occupied slot.  Returns True if any work was done.
         Newly submitted handles are picked up automatically."""
-        sched = self._ensure_scheduler()
-        self._enqueue_backlog(sched)
-        self._live = None
-        return sched.step()
+        self._counters["ticks"] += 1
+        with trace.step_span("serve.step", step_num=self._counters["ticks"]):
+            sched = self._ensure_scheduler()
+            self._enqueue_backlog(sched)
+            self._live = None
+            return sched.step()
 
     def drain(self, greedy: bool = True, seed: int = 0) -> None:
         """Step the continuous scheduler until every submitted request
@@ -762,13 +784,14 @@ class Engine:
                 break
 
             step += 1
-            t0 = time.monotonic()
             try:
-                logits, cache, path = self._execute(
-                    "serve.decode_step", step,
-                    lambda: self._decode(self.params, cache, tok[:, None]),
-                    lambda: self._decode_degraded(self.params, cache,
-                                                  tok[:, None]))
+                with trace.span("serve.decode", rows=len(active)) as sp:
+                    logits, cache, path = self._execute(
+                        "serve.decode_step", step,
+                        lambda: self._decode(self.params, cache,
+                                             tok[:, None]),
+                        lambda: self._decode_degraded(self.params, cache,
+                                                      tok[:, None]))
             except StepFailed as e:
                 self._fail_batch(reqs, e, step)
                 break
@@ -777,7 +800,7 @@ class Engine:
                 for r in reqs:
                     if r.state == RequestState.DECODING:
                         r.degraded_steps += 1
-            self.monitor.record(step, time.monotonic() - t0)
+            self.monitor.record(step, sp.seconds)
             self._live = (reqs, cache, logits, step, greedy, seed)
             if (self.snapshot_every and self.snapshots is not None
                     and step % self.snapshot_every == 0):
@@ -1080,6 +1103,7 @@ class Engine:
             out["journal"] = self.journal.stats()
         if self.snapshots is not None:
             out["snapshots"] = self.snapshots.stats()
+        out["trace"] = trace.report()
         return out
 
     def generate(self, prompts: np.ndarray, max_new_tokens: int,

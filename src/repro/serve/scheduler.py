@@ -77,7 +77,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models import layers, lm
-from repro.runtime import health
+from repro.runtime import health, trace
 from repro.serve.paged_cache import PagedKVCache, pages_for
 
 
@@ -265,7 +265,8 @@ class ContinuousScheduler:
     # -- admission ------------------------------------------------------
     def _admit(self) -> bool:
         if self._pf is not None:
-            return self._advance_chunked()
+            with trace.span("serve.admit", rid=self._pf[0].rid):
+                return self._advance_chunked()
         did = self._try_resume()
         if self.paused:
             # spilled requests resume before anyone new is admitted:
@@ -326,14 +327,17 @@ class ContinuousScheduler:
                 pages = list(reuse) + new
             self.waiting.popleft()
             req.queue_reason = None
-            self._ensure_cache()
-            self.t_start.setdefault(req.rid, time.monotonic())
-            self.eng._warm_autotune(1, plen)
-            if chunked:
-                self._pf = (req, None, 0, pages)
-                return self._advance_chunked()
-            return self._prefill_whole(req, free[0], pages=pages,
-                                       reuse=reuse, covered=covered)
+            with trace.span("serve.admit", rid=req.rid):
+                self._ensure_cache()
+                admitted = self.t_start.setdefault(req.rid, time.monotonic())
+                if req.admitted_s is None:
+                    req.admitted_s = admitted
+                self.eng._warm_autotune(1, plen)
+                if chunked:
+                    self._pf = (req, None, 0, pages)
+                    return self._advance_chunked()
+                return self._prefill_whole(req, free[0], pages=pages,
+                                           reuse=reuse, covered=covered)
         return did
 
     def _defer(self, req, reason: str) -> None:
@@ -358,6 +362,10 @@ class ContinuousScheduler:
                 and not self.paged.below_low():
             return False
         rid = self.paused[0]
+        with trace.span("serve.admit", rid=rid):
+            return self._resume(rid, free[0])
+
+    def _resume(self, rid: int, slot: int) -> bool:
         req, kv_len, entries = self.spilled[rid]
         while True:
             pages = self.paged.unspill(entries)
@@ -375,7 +383,6 @@ class ContinuousScheduler:
             return True
         self.paused.pop(0)
         del self.spilled[rid]
-        slot = free[0]
         req.state = self._E.RequestState.DECODING
         self.slots[slot] = req
         self.req_pages[rid] = pages
@@ -420,17 +427,18 @@ class ContinuousScheduler:
         req.state = RequestState.PREFILLING
         dev = jnp.asarray(prompt[None])
         try:
-            if covered:
-                logits, rcache = self._prefill_from_pages(
-                    prompt, reuse, covered)
-            else:
-                logits, rcache, path = self.eng._execute(
-                    "serve.prefill", self.step_count,
-                    lambda: self.eng._prefill(self.eng.params, dev),
-                    lambda: self.eng._prefill_degraded(self.eng.params,
-                                                       dev))
-                if path == "degraded":
-                    self.eng._counters["degraded_steps"] += 1
+            with trace.span("serve.prefill", rid=req.rid, plen=plen):
+                if covered:
+                    logits, rcache = self._prefill_from_pages(
+                        prompt, reuse, covered)
+                else:
+                    logits, rcache, path = self.eng._execute(
+                        "serve.prefill", self.step_count,
+                        lambda: self.eng._prefill(self.eng.params, dev),
+                        lambda: self.eng._prefill_degraded(
+                            self.eng.params, dev))
+                    if path == "degraded":
+                        self.eng._counters["degraded_steps"] += 1
         except self._E.StepFailed as e:
             self._fail(req, e)
             if pages is not None:
@@ -500,10 +508,11 @@ class ContinuousScheduler:
                                        dtype=self.eng.cfg.act_dtype)
             primary, degraded = self._chunk_fn(int(toks.shape[1]))
             start = jnp.asarray(pos, jnp.int32)
-            logits, rcache, path = self.eng._execute(
-                "serve.prefill", self.step_count,
-                lambda: primary(self.eng.params, rcache, toks, start),
-                lambda: degraded(self.eng.params, rcache, toks, start))
+            with trace.span("serve.prefill", rid=req.rid, plen=plen):
+                logits, rcache, path = self.eng._execute(
+                    "serve.prefill", self.step_count,
+                    lambda: primary(self.eng.params, rcache, toks, start),
+                    lambda: degraded(self.eng.params, rcache, toks, start))
             if path == "degraded":
                 self.eng._counters["degraded_steps"] += 1
         except self._E.StepFailed as e:
@@ -577,8 +586,10 @@ class ContinuousScheduler:
                     self.paged.release(reuse)
                 return
             pages = list(reuse) + new
-        self.paged.store(prompt, pages, covered,
-                         rcache["k"][:, 0], rcache["v"][:, 0])
+        with trace.span("serve.store", rid=req.rid,
+                        pages=len(pages) - covered // self.cc.page_size):
+            self.paged.store(prompt, pages, covered,
+                             rcache["k"][:, 0], rcache["v"][:, 0])
         self.req_pages[req.rid] = pages
 
     def _install(self, req, slot: int, rcache, plen: int,
@@ -597,7 +608,8 @@ class ContinuousScheduler:
             self.cache["index"] = self.cache["index"].at[slot].set(plen)
         req.state = self._E.RequestState.DECODING
         self.slots[slot] = req
-        self._emit(slot, first_logits)
+        with trace.span("serve.emit", rows=1):
+            self._emit(slot, first_logits)
 
     # -- the pressure ladder --------------------------------------------
     def _acquire_decode_page(self, slot: int) -> bool:
@@ -754,17 +766,28 @@ class ContinuousScheduler:
             self.last_step.pop(r.rid, None)
 
     def _decode(self) -> bool:
-        if self.use_paged:
-            return self._decode_paged()
-        RequestState = self._E.RequestState
-        evicted = self._sweep_deadlines()
-        active = [i for i, r in enumerate(self.slots) if r is not None]
-        if not active:
-            return evicted
-        self.step_count += 1
-        toks = jnp.asarray(self.last_tok[:, None].astype(np.int32))
+        """One decode step over every occupied slot, inside the
+        ``serve.decode`` span; its length is the step the health monitor
+        records."""
+        with trace.span("serve.decode") as sp:
+            did, rows = (self._decode_paged() if self.use_paged
+                         else self._decode_slots())
+            sp.set(rows=rows)
+        if rows:
+            self.eng.monitor.record(self.step_count, sp.seconds)
+        return did
+
+    def _decode_slots(self) -> Tuple[bool, int]:
+        """Decode off the contiguous slot cache; returns (work done, rows
+        decoded)."""
+        with trace.span("serve.prepare"):
+            evicted = self._sweep_deadlines()
+            active = [i for i, r in enumerate(self.slots) if r is not None]
+            if not active:
+                return evicted, 0
+            self.step_count += 1
+            toks = jnp.asarray(self.last_tok[:, None].astype(np.int32))
         cache = self.cache
-        t0 = time.monotonic()
         try:
             logits, cache, path = self.eng._execute(
                 "serve.decode_step", self.step_count,
@@ -775,65 +798,64 @@ class ContinuousScheduler:
             for i in active:
                 self._fail(self.slots[i], e)
                 self._free_slot(i)
-            return True
+            return True, 0
         self.cache = cache
         if path == "degraded":
             self.eng._counters["degraded_steps"] += 1
             for i in active:
                 self.slots[i].degraded_steps += 1
-        self.eng.monitor.record(self.step_count, time.monotonic() - t0)
-        logits_np = np.asarray(logits)
-        for i in active:
-            self._emit(i, logits_np[i])
+        self._emit_rows(active, logits)
         # park freed rows at index 0 so the cache state is a pure
         # function of the live requests (deterministic replay)
         occupied = np.asarray(
             [r is not None for r in self.slots], bool)
         self.cache["index"] = jnp.where(
             jnp.asarray(occupied), self.cache["index"], 0)
-        return True
+        return True, len(active)
 
-    def _decode_paged(self) -> bool:
+    def _decode_paged(self) -> Tuple[bool, int]:
         """One decode step straight off the page pool: grow rows at
         page boundaries (running the pressure ladder on failure), then
-        dispatch ``lm.paged_decode_step`` over the block tables."""
-        evicted = self._sweep_deadlines()
-        if not any(r is not None for r in self.slots):
-            return evicted
-        ps = self.cc.page_size
-        # page-boundary growth; the ladder may spill/preempt *other*
-        # slots while satisfying row i, so re-check liveness as we go
-        for i in range(self.cc.max_batch):
-            req = self.slots[i]
-            if req is None:
-                continue
-            if int(self.kv_lens[i]) // ps < len(self.req_pages[req.rid]):
-                continue
-            if not self._acquire_decode_page(i):
-                # ladder exhausted with the needy request the only
-                # holder left: recompute it later instead of wedging
-                self._preempt_slot(i)
-        active = [i for i, r in enumerate(self.slots) if r is not None]
-        if not active:
-            return True                    # the ladder did the work
-        self.step_count += 1
-        mb = self.cc.max_batch
-        tables = np.zeros((mb, self.max_pages), np.int32)
-        wp = np.full(mb, self.paged.scratch, np.int32)
-        wo = np.zeros(mb, np.int32)
-        for i in active:
-            pages = self.req_pages[self.slots[i].rid]
-            tables[i, :len(pages)] = pages
-            kv = int(self.kv_lens[i])
-            wp[i] = pages[kv // ps]
-            wo[i] = kv % ps
-        toks = jnp.asarray(self.last_tok[:, None].astype(np.int32))
-        tables_d = jnp.asarray(tables)
-        kv_d = jnp.asarray(self.kv_lens.astype(np.int32))
-        wp_d, wo_d = jnp.asarray(wp), jnp.asarray(wo)
+        dispatch ``lm.paged_decode_step`` over the block tables.
+        Returns (work done, rows decoded)."""
+        with trace.span("serve.prepare"):
+            evicted = self._sweep_deadlines()
+            if not any(r is not None for r in self.slots):
+                return evicted, 0
+            ps = self.cc.page_size
+            # page-boundary growth; the ladder may spill/preempt *other*
+            # slots while satisfying row i, so re-check liveness as we go
+            for i in range(self.cc.max_batch):
+                req = self.slots[i]
+                if req is None:
+                    continue
+                if int(self.kv_lens[i]) // ps < len(
+                        self.req_pages[req.rid]):
+                    continue
+                if not self._acquire_decode_page(i):
+                    # ladder exhausted with the needy request the only
+                    # holder left: recompute it later instead of wedging
+                    self._preempt_slot(i)
+            active = [i for i, r in enumerate(self.slots) if r is not None]
+            if not active:
+                return True, 0             # the ladder did the work
+            self.step_count += 1
+            mb = self.cc.max_batch
+            tables = np.zeros((mb, self.max_pages), np.int32)
+            wp = np.full(mb, self.paged.scratch, np.int32)
+            wo = np.zeros(mb, np.int32)
+            for i in active:
+                pages = self.req_pages[self.slots[i].rid]
+                tables[i, :len(pages)] = pages
+                kv = int(self.kv_lens[i])
+                wp[i] = pages[kv // ps]
+                wo[i] = kv % ps
+            toks = jnp.asarray(self.last_tok[:, None].astype(np.int32))
+            tables_d = jnp.asarray(tables)
+            kv_d = jnp.asarray(self.kv_lens.astype(np.int32))
+            wp_d, wo_d = jnp.asarray(wp), jnp.asarray(wo)
         k_pool, v_pool = self.paged.k_pages, self.paged.v_pages
         primary, degraded = self._paged_fns()
-        t0 = time.monotonic()
         try:
             logits, pools, path = self.eng._execute(
                 "serve.decode_step", self.step_count,
@@ -845,7 +867,7 @@ class ContinuousScheduler:
             for i in active:
                 self._fail(self.slots[i], e)
                 self._free_slot(i)
-            return True
+            return True, 0
         # commit the pools only on step success — same pre-step-cache
         # retry contract as the slot path
         self.paged.k_pages, self.paged.v_pages = pools
@@ -853,12 +875,19 @@ class ContinuousScheduler:
             self.eng._counters["degraded_steps"] += 1
             for i in active:
                 self.slots[i].degraded_steps += 1
-        self.eng.monitor.record(self.step_count, time.monotonic() - t0)
-        logits_np = np.asarray(logits)
         for i in active:
             self.kv_lens[i] += 1           # before _emit: it may free
-            self._emit(i, logits_np[i])
-        return True
+        self._emit_rows(active, logits)
+        return True, len(active)
+
+    def _emit_rows(self, active: List[int], logits) -> None:
+        """Bring the step's logits to the host and emit one token per
+        decoded row."""
+        with trace.span("serve.fetch"):
+            logits_np = np.asarray(logits)
+        with trace.span("serve.emit", rows=len(active)):
+            for i in active:
+                self._emit(i, logits_np[i])
 
     def _emit(self, slot: int, logits_row) -> None:
         """Sample one token for ``slot``, journal it, finish on budget."""

@@ -158,6 +158,20 @@ def test_note_and_report_rollup():
     assert rep["median_step_seconds"] == 0.0
 
 
+def test_monitor_memory_is_constant_in_the_steps_served():
+    mon = health.HealthMonitor(window=16, threshold=3.0)
+    stragglers = 0
+    for s in range(5000):
+        slow = s % 50 == 49
+        stragglers += mon.record(s, 1.0 if slow else 0.1)
+    assert stragglers == 100
+    assert len(mon.records) == 16 and len(mon.stragglers) == 16
+    assert mon.stragglers[-1].step == 4999
+    rep = mon.report()
+    assert rep["steps"] == 5000 and rep["stragglers"] == 100
+    assert rep["median_step_seconds"] == 0.1
+
+
 # ---------------------------------------------------------------------------
 # DegradationPolicy.
 # ---------------------------------------------------------------------------
