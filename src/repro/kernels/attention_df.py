@@ -38,11 +38,10 @@ Per-row banding (PR 8): ``kv_len`` may be a per-batch-row ``(R,)``
 array — ``make_band_info`` then builds an ``(R, 2)`` info array and
 every index map / mask derives its row as ``b // (bh // R)``, so a
 ragged continuous-batching decode step bands each request at its own
-valid length in ONE dispatch.  ``paged_flash_attention`` extends the
-same scalar-prefetch trick to a paged KV cache: the per-row block
-table is part of the prefetch array and the KV index maps dereference
-it to translate logical blocks into physical page ids (a page table
-*is* an index map).
+valid length in ONE dispatch.  ``paged_flash_attention`` decodes off a
+paged KV cache with one grid step per row: the lengths and block tables
+ride in SMEM, and the step gathers the row's pages by manual DMA, each
+page once for all heads, in a walk that stops at the row's length.
 
 int8 KV caches dequantize at the block load: K/V stream as int8 with
 per-position f32 scales (``k_scale``/``v_scale``, shape (BHkv, Skv, 1)),
@@ -592,58 +591,153 @@ def kv_stationary_attention(
 
 
 # ---------------------------------------------------------------------------
-# Paged (block-table) decode attention: a page table IS an index map.
+# Paged (block-table) decode attention: one grid step per row, walking
+# the row's pages in blocks fetched once for all heads.
 # ---------------------------------------------------------------------------
-def _paged_kernel(info_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
-                  l_ref, *, page: int, band: int, scale: float, heads: int,
-                  window: Optional[int]):
-    b, jr = pl.program_id(0), pl.program_id(1)
-    row = b // heads
-    kv_valid = info_ref[row, 0]
-    hi = jnp.maximum(0, (kv_valid + page - 1) // page - 1)
+# A block of the paged walk spans at least this many positions...
+PAGED_BLOCK_POSITIONS = 128
+# ...as long as its double-buffered K and V fit in this much VMEM.
+PAGED_BLOCK_VMEM = 4 * 2 ** 20
+
+
+def paged_pages_per_block(page: int, d: int, hkv: int, max_pages: int,
+                          itemsize: int) -> int:
+    """Pages per block of the paged walk, from shapes alone: enough for
+    ``PAGED_BLOCK_POSITIONS`` positions, no more than two K and two V
+    blocks of all ``hkv`` heads fit in ``PAGED_BLOCK_VMEM``, and no more
+    than a row's block table holds."""
+    want = pl.cdiv(PAGED_BLOCK_POSITIONS, page)
+    fit = PAGED_BLOCK_VMEM // (4 * hkv * page * d * itemsize)
+    return max(1, min(want, fit, max_pages))
+
+
+def _p_dot_v(p, v, v_ok):
+    """``p · v`` with float32 ``p`` and products, rows of ``v`` outside
+    ``v_ok`` taken as zeros.  A bf16 ``v`` meets ``p`` split exactly into
+    three bf16 parts (8 + 8 + 8 significant bits) stacked as rows of one
+    matmul: exact products, float32 sums, one pass over ``v``."""
+    if v.dtype != jnp.bfloat16:
+        v = jnp.where(v_ok, v.astype(jnp.float32), 0.0)
+        return jnp.dot(p, v, preferred_element_type=jnp.float32)
+    v = jnp.where(v_ok, v, jnp.zeros_like(v))
+    hi = p.astype(jnp.bfloat16)
+    rest = p - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    n = p.shape[0]
+    out = jnp.dot(jnp.concatenate([hi, mid, lo], axis=0), v,
+                  preferred_element_type=jnp.float32)
+    return out[:n] + out[n:2 * n] + out[2 * n:]
+
+
+def _paged_kernel(lens_ref, tables_ref, q_ref, k_hbm, v_hbm, o_ref,
+                  k_buf, v_buf, k_sems, v_sems, acc_ref, m_ref, l_ref, *,
+                  page: int, ppb: int, max_pages: int, group: int,
+                  scale: float, window: Optional[int]):
+    row = pl.program_id(0)
+    hkv, blk = k_buf.shape[1], k_buf.shape[2]
+    hq = acc_ref.shape[0]
+    kv_valid = lens_ref[row]
+    n_pages = jnp.minimum(pl.cdiv(kv_valid, page), max_pages)
     if window is not None:
-        lo = jnp.minimum(jnp.maximum(0, (kv_valid - window) // page), hi)
+        first = jnp.minimum(jnp.maximum(0, (kv_valid - window) // page),
+                            n_pages)
     else:
-        lo = jnp.zeros_like(hi)
-    jblk = jnp.minimum(lo + jr, hi)
+        first = jnp.int32(0)
+    n_blocks = pl.cdiv(n_pages - first, ppb)
 
-    @pl.when(jr == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def page_copies(j, slot, i):
+        # one strided copy moves a page of every KV head
+        pid = tables_ref[row * max_pages + first + j * ppb + i]
+        dst = pl.ds(pl.multiple_of(i * page, page), page)
+        return (pltpu.make_async_copy(k_hbm.at[:, pid],
+                                      k_buf.at[slot, :, dst],
+                                      k_sems.at[slot]),
+                pltpu.make_async_copy(v_hbm.at[:, pid],
+                                      v_buf.at[slot, :, dst],
+                                      v_sems.at[slot]))
 
-    @pl.when((lo + jr <= hi) & (kv_valid > 0))
-    def _update():
-        q = q_ref[0].astype(jnp.float32)                    # (1, d)
-        k = k_ref[0, 0].astype(jnp.float32)                 # (page, d)
-        v = v_ref[0, 0].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        kpos = jblk * page + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page), 1)
+    def in_block(j):
+        # pages of block j below the row's length; the rest stay unfetched
+        return jnp.minimum(ppb, n_pages - first - j * ppb)
+
+    def start(j, slot):
+        def body(i, c):
+            for cp in page_copies(j, slot, i):
+                cp.start()
+            return c
+        jax.lax.fori_loop(0, in_block(j), body, 0)
+
+    def wait(j, slot):
+        def body(i, c):
+            for cp in page_copies(j, slot, i):
+                cp.wait()
+            return c
+        jax.lax.fori_loop(0, in_block(j), body, 0)
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+    @pl.when(n_blocks > 0)
+    def _prefetch():
+        start(0, 0)
+
+    q = q_ref[...]                                          # (hq, d)
+    if q.dtype != k_buf.dtype:
+        q = q.astype(jnp.float32)
+    head = jax.lax.broadcasted_iota(jnp.int32, (hq, 1), 0) // group
+
+    def step(j, c):
+        slot = j % 2
+
+        @pl.when(j + 1 < n_blocks)
+        def _next():
+            start(j + 1, 1 - slot)
+
+        wait(j, slot)
+        pos0 = (first + j * ppb) * page
+        kpos = pos0 + jax.lax.broadcasted_iota(jnp.int32, (1, blk), 1)
         mask = kpos < kv_valid          # decode q row == position kv_valid-1
         if window is not None:
             mask &= kpos > kv_valid - 1 - window
-        s = jnp.where(mask, s, NEG_INF)
+        # positions past the row's length may sit in unfetched pages
+        vpos = pos0 + jax.lax.broadcasted_iota(jnp.int32, (blk, 1), 0)
+        v_ok = vpos < kv_valid
+        # every q row against each KV head's block; a row keeps its own
+        # head's scores (the group's heads share the fetch)
+        s = jnp.zeros((hq, blk), jnp.float32)
+        for h in range(hkv):
+            k = k_buf[slot, h]                              # (blk, d)
+            if k.dtype != q.dtype:
+                k = k.astype(jnp.float32)
+            sh = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            s = jnp.where(head == h, sh, s)
+        s = jnp.where(mask, s * scale, NEG_INF)
         m_prev = m_ref[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
         alpha = jnp.exp(m_prev - m_new)
         l_new = alpha * l_ref[:, :1] + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
+        pv = jnp.zeros(acc_ref.shape, jnp.float32)
+        for h in range(hkv):
+            pv += _p_dot_v(jnp.where(head == h, p, 0.0), v_buf[slot, h],
+                           v_ok)
+        acc_ref[...] = acc_ref[...] * alpha + pv
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+        return c
 
-    @pl.when(jr == band - 1)
-    def _flush():
-        l = l_ref[:, :1]
-        l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+    jax.lax.fori_loop(0, n_blocks, step, 0)
+    l = l_ref[:, :1]
+    l = jnp.where(l == 0.0, 1.0, l)
+    o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def paged_flash_attention(
-    q: jax.Array,             # (BH, 1, D)  folded rows*q_heads, decode
+    q: jax.Array,             # (R, HQ, D) decode queries, one row each
     k_pages: jax.Array,       # (HKV, P, page, D) shared page pool
     v_pages: jax.Array,
     block_tables: jax.Array,  # (R, max_pages) int32 page ids per row
@@ -653,80 +747,73 @@ def paged_flash_attention(
     window: Optional[int] = None,
     interpret: bool = False,
 ) -> jax.Array:
-    """OS-anchored decode attention over a paged KV cache.
+    """OS-anchored decode attention over a paged KV cache: (R, HQ, D).
 
-    The block indirection rides the same ``PrefetchScalarGridSpec``
-    machinery as the banded kernels — the scalar-prefetch array is
-    ``concat([kv_lens[:, None], block_tables], axis=1)`` and the KV
-    index maps dereference it twice: batch row ``b // heads`` selects
-    the row's band (exactly the per-row ``[kv_valid, window]`` clamp of
-    :func:`flash_attention`), then ``info[row, 1 + jblk]`` translates
-    the row's logical KV block into a physical page id.  A page table
-    *is* an index map: no gather materializes a contiguous cache, the
-    DMA engine walks the pool directly.
+    One grid step per row.  The row's queries of all ``HQ`` heads are
+    the anchored operand: the output accumulator and the online-softmax
+    statistics stay in VMEM for the whole walk, and the output is
+    written once.  K and V stay in HBM; the step walks the row's pages
+    in blocks of ``ppb`` pages with an in-kernel loop whose trip count
+    comes from the row's length, so the grid does not grow with
+    ``max_pages`` and a row at length 0 fetches nothing and writes
+    zeros.  Each page is one strided copy of all KV heads, gathered
+    from the block table (in SMEM with the lengths) into a
+    double-buffered VMEM block while the previous block computes; the
+    ``group`` q heads of a KV head share that fetch.
 
-    Each logical block spans exactly one page (``bkv == page``).  Steps
-    beyond a row's last valid page clamp onto it (a revisited page id —
-    no new DMA) and skip compute; a row at ``kv_len == 0`` dereferences
-    table slot 0 (tables must default to a valid id, 0 by convention)
-    and writes zeros.  Float pools only — the int8-KV scale sidecar
-    stays on the contiguous path.
+    Only pages below ``ceil(kv_len / page)`` are fetched, and with a
+    static ``window`` only those from the page holding the band's first
+    position on; the masks drop positions outside the band within the
+    pages that are fetched.  ``ppb`` comes from shapes alone
+    (:func:`paged_pages_per_block`): a block of at least
+    ``PAGED_BLOCK_POSITIONS`` positions whose double-buffered K and V
+    fit ``PAGED_BLOCK_VMEM``, at most ``max_pages``.  Scores and ``p·V``
+    accumulate in float32.  Table slots past a row's pages are never
+    read (0 by convention).  Float pools only; the int8-KV scale
+    sidecar stays on the contiguous path.
     """
-    bh, sq, d = q.shape
-    if sq != 1:
-        raise ValueError(f"paged attention is decode-only (sq=1), got {sq}")
-    hkv, n_pages, page, _ = k_pages.shape
-    rows, max_pages = block_tables.shape
-    if bh % rows:
-        raise ValueError(f"bh={bh} not divisible by rows={rows}")
-    heads = bh // rows
-    if heads != hkv * group:
+    rows, hq, d = q.shape
+    hkv, _, page, _ = k_pages.shape
+    max_pages = block_tables.shape[1]
+    if block_tables.shape[0] != rows:
         raise ValueError(
-            f"{heads} q heads per row != pool heads {hkv} * group {group}"
+            f"{block_tables.shape[0]} block tables for {rows} rows")
+    if hq != hkv * group:
+        raise ValueError(
+            f"{hq} q heads per row != pool heads {hkv} * group {group}"
         )
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
-    band = max(1, max_pages)
-    info = jnp.concatenate([
-        jnp.asarray(kv_lens, jnp.int32).reshape(rows, 1),
-        jnp.asarray(block_tables, jnp.int32).reshape(rows, max_pages),
-    ], axis=1)
-
-    def page_block(b, jr, info_ref):
-        row = b // heads
-        kv_valid = info_ref[row, 0]
-        hi = jnp.maximum(0, (kv_valid + page - 1) // page - 1)
-        if window is not None:
-            lo = jnp.minimum(jnp.maximum(0, (kv_valid - window) // page),
-                             hi)
-        else:
-            lo = jnp.zeros_like(hi)
-        jblk = jnp.minimum(lo + jr, hi)
-        return info_ref[row, 1 + jblk]          # page table -> index map
-
-    kv_spec = pl.BlockSpec(
-        (1, 1, page, d),
-        lambda b, jr, info, g=group:
-        ((b % heads) // g, page_block(b, jr, info), 0, 0))
+    ppb = paged_pages_per_block(page, d, hkv, max_pages,
+                                jnp.dtype(k_pages.dtype).itemsize)
+    blk = ppb * page
     kernel = functools.partial(
-        _paged_kernel, page=page, band=band, scale=scale, heads=heads,
-        window=window,
+        _paged_kernel, page=page, ppb=ppb, max_pages=max_pages,
+        group=group, scale=scale, window=window,
     )
+    row_spec = pl.BlockSpec((None, hq, d), lambda r, lens, tables: (r, 0, 0))
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(bh, band),
+            num_scalar_prefetch=2,
+            grid=(rows,),
             in_specs=[
-                pl.BlockSpec((1, 1, d), lambda b, jr, info: (b, 0, 0)),
-                kv_spec, kv_spec,
+                row_spec,
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=pl.BlockSpec((1, 1, d), lambda b, jr, info: (b, 0, 0)),
+            out_specs=row_spec,
             scratch_shapes=[
-                pltpu.VMEM((1, d), jnp.float32),
-                pltpu.VMEM((1, 128), jnp.float32),
-                pltpu.VMEM((1, 128), jnp.float32),
+                pltpu.VMEM((2, hkv, blk, d), k_pages.dtype),
+                pltpu.VMEM((2, hkv, blk, d), v_pages.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((hq, d), jnp.float32),
+                pltpu.VMEM((hq, 128), jnp.float32),
+                pltpu.VMEM((hq, 128), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((bh, 1, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((rows, hq, d), q.dtype),
         interpret=interpret,
-    )(info, q, k_pages, v_pages)
+    )(jnp.asarray(kv_lens, jnp.int32).reshape(rows),
+      jnp.asarray(block_tables, jnp.int32).reshape(rows * max_pages),
+      q, k_pages, v_pages)

@@ -543,13 +543,14 @@ def paged_attention(
 ) -> jax.Array:
     """Decode attention straight off a paged KV cache. Returns (B, Hq, 1, D).
 
-    The block-table indirection rides the kernel's scalar-prefetch index
-    map — a page table *is* an index map (see docs/serving.md) — so each
-    row's KV stream gathers its own pages with no contiguous copy, and
-    out-of-band grid steps clamp onto the band edge (no DMA, no
-    compute).  The xla/oracle path gathers pages into a contiguous
-    per-row cache and defers to ``ref.attention_ref`` with ragged
-    ``kv_len``.  Decode-only: ``Sq == 1``.
+    The kernel (``attention_df.paged_flash_attention``) runs one grid
+    step per row: it walks the row's pages in blocks, each page fetched
+    once for all heads by a copy gathered through the block table (see
+    docs/serving.md), and stops at the row's length, so the work
+    follows each row's valid length, not ``max_pages``.  The xla/oracle
+    path gathers pages into a contiguous per-row cache and defers to
+    ``ref.attention_ref`` with ragged ``kv_len``.  Decode-only:
+    ``Sq == 1``.
     """
     fault = _inject("kernel.attention")
     b, hq, sq, d = q.shape
@@ -567,7 +568,7 @@ def paged_attention(
             ref.attention_ref(q, kg, vg, causal=True, window=window,
                               scale=scale, kv_len=kv_lens), fault)
     out = attention_df.paged_flash_attention(
-        q.reshape(b * hq, 1, d), k_pages, v_pages, block_tables, kv_lens,
+        q.reshape(b, hq, d), k_pages, v_pages, block_tables, kv_lens,
         group=group, scale=scale, window=window,
         interpret=backend == "interpret",
     )

@@ -122,8 +122,8 @@ from repro.runtime import elastic, health, trace
 from repro.serve import journal as journal_lib
 from repro.serve.paged_cache import pages_for
 from repro.serve.scheduler import (ContinuousScheduler, SamplingParams,
-                                   SchedulerConfig, paged_decode_enabled,
-                                   pool_capacity)
+                                   SchedulerConfig, decode_kv_pages,
+                                   paged_decode_enabled, pool_capacity)
 
 health.register_site("snapshot.save")
 health.register_site("engine.restore")
@@ -740,6 +740,7 @@ class Engine:
             for _ in range(step):
                 key, _ = jax.random.split(key)
         self._live = (reqs, cache, logits, step, greedy, seed)
+        page_size = (self.scheduler_config or SchedulerConfig()).page_size
         while True:
             active = [r for r in reqs if r.state == RequestState.DECODING]
             if not active:
@@ -785,7 +786,9 @@ class Engine:
 
             step += 1
             try:
-                with trace.span("serve.decode", rows=len(active)) as sp:
+                with trace.span("serve.decode", rows=len(active),
+                                kv_pages=decode_kv_pages(
+                                    active, page_size)) as sp:
                     logits, cache, path = self._execute(
                         "serve.decode_step", step,
                         lambda: self._decode(self.params, cache,

@@ -139,6 +139,14 @@ def pool_capacity(sc: Optional[SchedulerConfig], max_len: int) -> int:
     return sc.n_pages or sc.max_batch * pages_for(max_len, sc.page_size)
 
 
+def decode_kv_pages(reqs, page_size: int) -> int:
+    """The KV a decode step's rows attend over, in pages, summed: each
+    request's prompt and emitted tokens (the last is the step's input).
+    On the page pool these are the pages the paged attention reads."""
+    return sum(pages_for(len(r.prompt) + len(r.out_tokens), page_size)
+               for r in reqs)
+
+
 class ContinuousScheduler:
     """Slot-based continuous batching over one ``Engine``.
 
@@ -770,22 +778,24 @@ class ContinuousScheduler:
         ``serve.decode`` span; its length is the step the health monitor
         records."""
         with trace.span("serve.decode") as sp:
-            did, rows = (self._decode_paged() if self.use_paged
-                         else self._decode_slots())
-            sp.set(rows=rows)
+            did, rows, kv_pages = (self._decode_paged() if self.use_paged
+                                   else self._decode_slots())
+            sp.set(rows=rows, kv_pages=kv_pages)
         if rows:
             self.eng.monitor.record(self.step_count, sp.seconds)
         return did
 
-    def _decode_slots(self) -> Tuple[bool, int]:
+    def _decode_slots(self) -> Tuple[bool, int, int]:
         """Decode off the contiguous slot cache; returns (work done, rows
-        decoded)."""
+        decoded, their KV in pages)."""
         with trace.span("serve.prepare"):
             evicted = self._sweep_deadlines()
             active = [i for i, r in enumerate(self.slots) if r is not None]
             if not active:
-                return evicted, 0
+                return evicted, 0, 0
             self.step_count += 1
+            kv_pages = decode_kv_pages([self.slots[i] for i in active],
+                                       self.cc.page_size)
             toks = jnp.asarray(self.last_tok[:, None].astype(np.int32))
         cache = self.cache
         try:
@@ -798,7 +808,7 @@ class ContinuousScheduler:
             for i in active:
                 self._fail(self.slots[i], e)
                 self._free_slot(i)
-            return True, 0
+            return True, 0, 0
         self.cache = cache
         if path == "degraded":
             self.eng._counters["degraded_steps"] += 1
@@ -811,17 +821,17 @@ class ContinuousScheduler:
             [r is not None for r in self.slots], bool)
         self.cache["index"] = jnp.where(
             jnp.asarray(occupied), self.cache["index"], 0)
-        return True, len(active)
+        return True, len(active), kv_pages
 
-    def _decode_paged(self) -> Tuple[bool, int]:
+    def _decode_paged(self) -> Tuple[bool, int, int]:
         """One decode step straight off the page pool: grow rows at
         page boundaries (running the pressure ladder on failure), then
         dispatch ``lm.paged_decode_step`` over the block tables.
-        Returns (work done, rows decoded)."""
+        Returns (work done, rows decoded, pages the attention reads)."""
         with trace.span("serve.prepare"):
             evicted = self._sweep_deadlines()
             if not any(r is not None for r in self.slots):
-                return evicted, 0
+                return evicted, 0, 0
             ps = self.cc.page_size
             # page-boundary growth; the ladder may spill/preempt *other*
             # slots while satisfying row i, so re-check liveness as we go
@@ -838,8 +848,10 @@ class ContinuousScheduler:
                     self._preempt_slot(i)
             active = [i for i, r in enumerate(self.slots) if r is not None]
             if not active:
-                return True, 0             # the ladder did the work
+                return True, 0, 0          # the ladder did the work
             self.step_count += 1
+            kv_pages = decode_kv_pages([self.slots[i] for i in active],
+                                       self.cc.page_size)
             mb = self.cc.max_batch
             tables = np.zeros((mb, self.max_pages), np.int32)
             wp = np.full(mb, self.paged.scratch, np.int32)
@@ -867,7 +879,7 @@ class ContinuousScheduler:
             for i in active:
                 self._fail(self.slots[i], e)
                 self._free_slot(i)
-            return True, 0
+            return True, 0, 0
         # commit the pools only on step success — same pre-step-cache
         # retry contract as the slot path
         self.paged.k_pages, self.paged.v_pages = pools
@@ -878,7 +890,7 @@ class ContinuousScheduler:
         for i in active:
             self.kv_lens[i] += 1           # before _emit: it may free
         self._emit_rows(active, logits)
-        return True, len(active)
+        return True, len(active), kv_pages
 
     def _emit_rows(self, active: List[int], logits) -> None:
         """Bring the step's logits to the host and emit one token per

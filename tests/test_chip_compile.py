@@ -28,7 +28,6 @@ MAX_BATCH = 8
 PAGE_SIZE = 16
 MAX_LEN = 2048
 PROMPT_LENS = (128, 512)
-N_PAGES = MAX_BATCH * MAX_LEN // PAGE_SIZE
 BF16, I32 = jnp.bfloat16, jnp.int32
 
 
@@ -67,16 +66,26 @@ def _custom_calls(lowered) -> int:
     return lowered.compile().as_text().count("tpu_custom_call")
 
 
-def test_paged_decode_attention_compiles(one_chip):
+# the serving benchmark's decode shapes: max_batch 16, max_len 2048,
+# page 16, d_head 128; q / KV heads of qwen3-1.7b and mistral-nemo-12b
+BENCH_BATCH = 16
+BENCH_DECODE_HEADS = {"qwen3-1.7b": (16, 8), "mistral-nemo-12b": (32, 8)}
+
+
+@pytest.mark.parametrize("model", sorted(BENCH_DECODE_HEADS))
+def test_paged_decode_attention_compiles(one_chip, model):
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    pool = s((CFG.n_kv_heads, N_PAGES + 1, PAGE_SIZE, CFG.d_head), BF16)
+    hq, hkv = BENCH_DECODE_HEADS[model]
+    d = CFG.d_head
+    n_pages = BENCH_BATCH * MAX_LEN // PAGE_SIZE
+    pool = s((hkv, n_pages + 1, PAGE_SIZE, d), BF16)
     lowered = ops.paged_attention.lower(
-        s((MAX_BATCH, CFG.n_heads, 1, CFG.d_head), BF16), pool, pool,
-        s((MAX_BATCH, MAX_LEN // PAGE_SIZE), I32), s((MAX_BATCH,), I32),
-        scale=CFG.d_head ** -0.5, backend="pallas")
-    assert _custom_calls(lowered) >= 1
+        s((BENCH_BATCH, hq, 1, d), BF16), pool, pool,
+        s((BENCH_BATCH, MAX_LEN // PAGE_SIZE), I32), s((BENCH_BATCH,), I32),
+        scale=d ** -0.5, backend="pallas")
+    assert _custom_calls(lowered) == 1
 
 
 @pytest.mark.parametrize("seq", PROMPT_LENS)

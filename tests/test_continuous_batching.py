@@ -112,6 +112,65 @@ def test_paged_vs_contiguous_equivalence():
                                    err_msg=f"backend={backend}")
 
 
+def _paged_pool(k, v, kv, page, max_pages, seed=0):
+    """The rows' K/V scattered over a shuffled pool; table pad slots
+    hold page 0, which is filled with NaN so that any read of it shows."""
+    hkv, d = k.shape[1], k.shape[-1]
+    n_pages = sum(pages_for(int(n), page) for n in kv) + 1
+    pool_k = np.zeros((hkv, n_pages, page, d), np.float32)
+    pool_v = np.zeros((hkv, n_pages, page, d), np.float32)
+    pool_k[:, 0] = pool_v[:, 0] = np.nan
+    tables = np.zeros((len(kv), max_pages), np.int32)
+    ids = iter(np.random.default_rng(seed).permutation(n_pages - 1) + 1)
+    for r, n in enumerate(kv):
+        for j in range(pages_for(int(n), page)):
+            lo, hi = j * page, min((j + 1) * page, int(n))
+            pid = next(ids)
+            pool_k[:, pid, :hi - lo] = np.asarray(k)[r, :, lo:hi]
+            pool_v[:, pid, :hi - lo] = np.asarray(v)[r, :, lo:hi]
+            tables[r, j] = pid
+    return (jnp.asarray(pool_k, k.dtype), jnp.asarray(pool_v, v.dtype),
+            jnp.asarray(tables))
+
+
+# page 8 with d 16: blocks of 16 pages (128 positions); 40 pages a row
+PAGED_MAX_LEN = 320
+RAGGED = [0, 1, 8, 9, 300, PAGED_MAX_LEN]
+PAGED_WALKS = {
+    # ragged lengths: empty, one position, one page, a page and one,
+    # three blocks with a partial last one, the whole table
+    **{f"ragged-group{g}": (g, RAGGED, None, jnp.float32)
+       for g in (1, 2, 4)},
+    # the band starts mid-page and mid-block (300 - 150) and spans
+    # two blocks
+    "window": (2, [5, 40, 300, PAGED_MAX_LEN], 150, jnp.float32),
+    "all-empty": (2, [0, 0, 0], None, jnp.float32),
+    # bf16 pools: p meets V unrounded, so float32 queries still get
+    # float32 results
+    "bf16-pool": (2, RAGGED, None, jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("walk", sorted(PAGED_WALKS))
+def test_paged_attention_walk(walk):
+    group, lens, window, pool_dtype = PAGED_WALKS[walk]
+    page, d, hkv = 8, 16, 2
+    kv = np.asarray(lens, np.int32)
+    q, k, v = _qkv(len(kv), hkv * group, hkv, 1, PAGED_MAX_LEN, d, seed=3)
+    k, v = k.astype(pool_dtype), v.astype(pool_dtype)
+    pool_k, pool_v, tables = _paged_pool(k, v, kv, page,
+                                         PAGED_MAX_LEN // page)
+    got = ops.paged_attention(q, pool_k, pool_v, tables, jnp.asarray(kv),
+                              window=window, backend="interpret")
+    want = ref.attention_ref(q, k.astype(jnp.float32),
+                             v.astype(jnp.float32), causal=True,
+                             window=window, kv_len=jnp.asarray(kv))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+    # a row with no valid KV attends to nothing
+    assert np.all(np.asarray(got)[kv == 0] == 0.0)
+
+
 def test_paged_attention_rejects_prefill_queries():
     q = jnp.zeros((1, 2, 4, 16))            # Sq=4: not a decode step
     pool = jnp.zeros((2, 4, 8, 16))

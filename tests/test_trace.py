@@ -186,6 +186,51 @@ def test_every_tick_records_the_span_tree(params):
                           "compile_s"}
 
 
+def test_decode_span_counts_the_pages_the_kernel_reads(params):
+    eng = _engine(params)
+    sched = eng._ensure_scheduler()
+    assert sched.use_paged
+    primary, degraded = sched._paged_fns()
+    dispatched = []
+
+    def recording(params, kp, vp, toks, tables, kv, wpid, woff):
+        dispatched.append((np.asarray(kv), np.asarray(wpid)))
+        return primary(params, kp, vp, toks, tables, kv, wpid, woff)
+
+    sched._paged_jit = (recording, degraded)
+    since = time.monotonic_ns()
+    # 20 new tokens take rows of 5-19 prompt tokens across page bounds
+    handles = [eng.submit(p, 20) for p in _prompts(3, seed=5)]
+    while not all(h.state == RequestState.DONE for h in handles):
+        eng.step()
+    decodes = [s for s in trace.spans(since)
+               if s.name == "serve.decode" and s.attrs["rows"]]
+    assert len(decodes) == len(dispatched)
+    page = sched.cc.page_size
+    for sp, (kv, wpid) in zip(decodes, dispatched):
+        # active rows write to their own pages, idle rows to scratch;
+        # the kernel attends each active row over kv + 1 positions
+        active = wpid != sched.paged.scratch
+        assert sp.attrs["rows"] == int(active.sum())
+        assert sp.attrs["kv_pages"] == sum(
+            -(-(int(n) + 1) // page) for n in kv[active])
+    assert max(s.attrs["kv_pages"] for s in decodes) > 2
+
+
+def test_batch_decode_span_counts_the_pages_it_attends(params):
+    eng = _engine(params)
+    rng = np.random.default_rng(6)
+    reqs = [eng.submit(rng.integers(0, CFG.vocab_size, 14).astype(np.int32),
+                       6) for _ in range(2)]
+    since = time.monotonic_ns()
+    eng.serve(reqs)           # equal prompt lengths: the batch loop
+    assert all(r.state == RequestState.DONE for r in reqs)
+    decodes = [s for s in trace.spans(since) if s.name == "serve.decode"]
+    # decode k attends over the 14 prompt positions and k tokens
+    assert [s.attrs["kv_pages"] for s in decodes] == [
+        len(reqs) * -(-(14 + k) // 16) for k in range(1, 6)]
+
+
 def test_admission_time_survives_a_request_done_in_one_tick(params):
     eng = _engine(params)
     h = eng.submit(_prompts(1, seed=4)[0], 1)
