@@ -2,7 +2,20 @@
 root names the cell, its configuration and its traffic mix; each of those
 is a file of its own under this directory.
 
-    configs/<config>.json      the configuration as it is run
+    configs/<config>.json      the configuration as it is run; its
+                               ``architecture`` and ``reference`` keys
+                               name the two files below
+    architectures/<name>.py    the harness's side of an architecture:
+                               ``arch_config(config)``, the program's
+                               ``ArchConfig``; ``build(key, config)``,
+                               the seeded weight tree in the program's
+                               layout (from ``weights.py``'s helpers);
+                               ``prefill_flops(c, plen)``,
+                               ``decode_flops(c, context)`` and
+                               ``paged_attention_call(c, contexts)``,
+                               summed over the layers, for ``work.py``;
+                               and what a reader of its cells takes of
+                               it (``mlp_gemms`` for the GEMM roofline)
     traffic/<mix>.json         the mix's parameters (``traffic.py`` reads it)
     metrics/<metric>.py        the reader of a per-layer metric; metrics
                                that differ only in their last part
@@ -12,12 +25,13 @@ is a file of its own under this directory.
     limits/<cell>.json         the limits that decide ``correct``
     offered/<cell>.json        an open-loop cell's offered rate
 
-Adding a cell, a mix, a configuration or a metric adds files and entries;
-no file here changes.
+Adding a cell, a mix, a configuration, an architecture or a metric adds
+files and entries; no file here changes.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 from pathlib import Path
@@ -59,7 +73,7 @@ def find_cell(name: str, bench: Optional[Dict[str, Any]] = None,
                        f"{sorted(cells)}")
     w = cells[name]
     configs = {c["name"]: c for c in bench["configs"]}
-    config = json.loads((root / configs[w["config"]]["file"]).read_text())
+    config = load_config(root / configs[w["config"]]["file"])
     limits = _json_or_empty(root / SUBDIR / "limits" / f"{name}.json")
     offered = _json_or_empty(root / SUBDIR / "offered" / f"{name}.json")
     return Cell(
@@ -68,6 +82,15 @@ def find_cell(name: str, bench: Optional[Dict[str, Any]] = None,
         end_to_end=[m for m in bench["end_to_end"] if _reports(m, name)],
         per_layer=[m for m in bench["per_layer"] if _reports(m, name)],
         limits=limits, offered=offered)
+
+
+def load_config(path: Path) -> Dict[str, Any]:
+    """A configuration file; it has to name its architecture."""
+    config = json.loads(path.read_text())
+    if "architecture" not in config:
+        raise ValueError(f"{path} names no \"architecture\": the file "
+                         f"architectures/<name>.py its model is run with")
+    return config
 
 
 def _json_or_empty(path: Path) -> Dict[str, Any]:
@@ -107,20 +130,15 @@ def reference(name: str, root: Path = ROOT) -> ModuleType:
                         "serve_reference_" + name)
 
 
-def arch_config(config: Dict[str, Any]):
-    """The program's ``ArchConfig`` for a configuration file: the
-    published keys mapped onto the program's names, nothing else."""
-    from repro.configs.base import ArchConfig
+@functools.lru_cache(maxsize=None)
+def architecture(name: str, root: Path = ROOT) -> ModuleType:
+    """``architectures/<name>.py``, loaded once: what the harness needs
+    of the architecture a configuration names."""
+    return _load_module(root / SUBDIR / "architectures" / f"{name}.py",
+                        "serve_architecture_" + name)
 
-    return ArchConfig(
-        name=config.get("model_type", "dense"), family="dense",
-        n_layers=config["num_hidden_layers"], d_model=config["hidden_size"],
-        n_heads=config["num_attention_heads"],
-        n_kv_heads=config["num_key_value_heads"],
-        d_ff=config["intermediate_size"], vocab_size=config["vocab_size"],
-        d_head=config["head_dim"], qk_norm=bool(config["qk_norm"]),
-        rope_theta=float(config["rope_theta"]),
-        norm_eps=float(config["rms_norm_eps"]),
-        tie_embeddings=bool(config["tie_word_embeddings"]),
-        param_dtype=config["torch_dtype"], act_dtype=config["torch_dtype"],
-        use_pallas_kernels=bool(config["serving"]["use_pallas_kernels"]))
+
+def arch_config(config: Dict[str, Any]):
+    """The program's ``ArchConfig`` for a configuration file, from its
+    architecture."""
+    return architecture(config["architecture"]).arch_config(config)

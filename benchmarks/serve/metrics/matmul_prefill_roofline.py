@@ -1,8 +1,10 @@
 """Kernels: the dataflow GEMM kernels' share of their roofline inside the
 prefill programs (%).  The least time is 2*M*K*N and each operand's
-bytes at the chip's peaks (``work.gemm``) for the three SwiGLU GEMMs of
-every layer of every prompt prefilled in the window; the kernels' time
-is their device time in the prefill programs."""
+bytes at the chip's peaks (``work.gemm``) for the MLP GEMMs of every
+layer, as the configuration's architecture lists them (``mlp_gemms``),
+of every prompt prefilled in the window; the kernels' time is their
+device time in the prefill programs."""
+import catalog
 import tracing
 import work
 
@@ -12,11 +14,12 @@ def read(run):
         return None
     kernel_s = tracing.kernel_seconds(run.trace, tracing.PREFILL_PROGRAM,
                                       tracing.MATMUL_KERNEL)
+    mlp_gemms = catalog.architecture(run.config["architecture"]).mlp_gemms
     least = 0.0
     for s in run.steps:
         if s.prefill_plen:
-            for m, k, n in work.mlp_gemms(run.config, s.prefill_plen):
-                least += run.config["num_hidden_layers"] * work.least_seconds(
+            for layers, m, k, n in mlp_gemms(run.config, s.prefill_plen):
+                least += layers * work.least_seconds(
                     *work.gemm(run.config, m, k, n), run.peak)
     if not kernel_s or not least:
         return None

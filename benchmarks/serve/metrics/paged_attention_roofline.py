@@ -1,8 +1,8 @@
 """Kernels: the paged decode attention kernel's share of its roofline (%).
 The least time is what the bytes and operations of each row's real
-context need at the chip's peaks (``work.paged_attention_call``), once
-per layer and step; the kernel's time is its device time in the decode
-programs of the window."""
+context need at the chip's peaks (``work.paged_attention_call``, summed
+over the layers), once per step; the kernel's time is its device time in
+the decode programs of the window."""
 import tracing
 import work
 
@@ -15,9 +15,8 @@ def read(run):
     least = 0.0
     for s in run.steps:
         if s.contexts:
-            flops, nbytes = work.paged_attention_call(run.config, s.contexts)
-            least += run.config["num_hidden_layers"] * work.least_seconds(
-                flops, nbytes, run.peak)
+            least += work.least_seconds(
+                *work.paged_attention_call(run.config, s.contexts), run.peak)
     if not kernel_s or not least:
         return None
     return 100.0 * least / kernel_s

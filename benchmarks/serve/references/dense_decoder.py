@@ -6,7 +6,8 @@ each query and key head, a residual add, RMSNorm, a SwiGLU MLP and a
 residual add; a final RMSNorm and the output head.  Written from that
 description in straightforward ``jax.numpy``; it imports nothing of the
 program.  The weights are made again from the seed, one layer at a time
-(``weights.py``), and raised from the served type to float32.
+(``architectures/dense_decoder.py`` and ``weights.py``), and raised from
+the served type to float32.
 
 ``control=True`` computes the same forward with every matmul operand
 rounded to 4 significant bits, which is fp8 e4m3 with an ideal scale:
@@ -21,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+import catalog
 import weights
 
 HI = jax.lax.Precision.HIGHEST
@@ -90,7 +92,7 @@ def _layer(x, lp, config_items: Tuple, control: bool):
 
 @functools.partial(jax.jit, static_argnames=("config_items",))
 def _layer_weights(key, config_items: Tuple):
-    lp = weights.layer(key, dict(config_items))
+    lp = catalog.architecture("dense_decoder").layer(key, dict(config_items))
     return jax.tree.map(lambda a: a.astype(jnp.float32), lp)
 
 

@@ -32,12 +32,11 @@ def main(config_name: str) -> int:
     from jax.sharding import SingleDeviceSharding
 
     import catalog
-    import weights
 
     bench = catalog.load_benchmark()
     entry = {c["name"]: c for c in bench["configs"]}[config_name]
-    import json
-    config = json.loads((catalog.ROOT / entry["file"]).read_text())
+    config = catalog.load_config(catalog.ROOT / entry["file"])
+    arch = catalog.architecture(config["architecture"])
     lengths = sorted({n for w in bench["workloads"]
                       if w["config"] == config_name
                       for n in catalog.mix(catalog.find_cell(w["name"])
@@ -52,14 +51,14 @@ def main(config_name: str) -> int:
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     chip = SingleDeviceSharding(topo.devices[0])
-    cfg = catalog.arch_config(config)
+    cfg = arch.arch_config(config)
     s = config["serving"]
 
     def sds(tree):
         return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
             a.shape, a.dtype, sharding=chip), tree)
 
-    params = sds(jax.eval_shape(lambda k: weights.build(k, config),
+    params = sds(jax.eval_shape(lambda k: arch.build(k, config),
                                 jax.random.PRNGKey(0)))
 
     def report(name, fn, *args):

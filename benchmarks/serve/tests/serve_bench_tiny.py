@@ -17,7 +17,8 @@ TINY_GAP_LIMIT = 0.15
 
 
 def config(tied: bool = True, dtype: str = "bfloat16") -> dict:
-    return {"reference": "dense_decoder", "model_type": "tiny",
+    return {"reference": "dense_decoder", "architecture": "dense_decoder",
+            "model_type": "tiny",
             "hidden_size": 64, "intermediate_size": 128,
             "num_hidden_layers": 2, "num_attention_heads": 4,
             "num_key_value_heads": 2, "head_dim": 16, "vocab_size": 500,

@@ -2,6 +2,7 @@
 names and units; every name resolves to a file of its own."""
 import json
 import re
+import shutil
 
 import pytest
 
@@ -13,6 +14,8 @@ BENCH = json.loads(BENCH_FILE.read_text())
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
 FILE_PART = re.compile(r"^[A-Za-z0-9_.-]+$")
+ARCH_FUNCTIONS = ("arch_config", "build", "prefill_flops", "decode_flops",
+                  "paged_attention_call")
 KEYS = {
     "configs": {"name", "source", "file", "reduced", "why"},
     "workloads": {"name", "config", "traffic", "chips", "why"},
@@ -98,6 +101,10 @@ def test_every_name_resolves_to_its_file():
         config = json.loads(path.read_text())
         assert config["reduced"] == c["reduced"]
         assert (SERVE / "references" / f"{config['reference']}.py").is_file()
+        arch = SERVE / "architectures" / f"{config['architecture']}.py"
+        assert arch.is_file()
+        module = catalog.architecture(config["architecture"])
+        assert all(callable(getattr(module, f)) for f in ARCH_FUNCTIONS)
     assert len({c["file"] for c in BENCH["configs"]}) == len(BENCH["configs"])
     for w in BENCH["workloads"]:
         assert catalog.traffic_file(w["traffic"]).is_file()
@@ -107,6 +114,21 @@ def test_every_name_resolves_to_its_file():
     for m in BENCH["per_layer"]:
         assert callable(catalog.metric_reader(m["name"]).read)
     assert BENCH["paths"] == [str(catalog.SUBDIR)]
+
+
+def test_a_configuration_without_an_architecture_names_its_file(tmp_path):
+    shutil.copy(BENCH_FILE, tmp_path)
+    entry = BENCH["configs"][0]
+    config = json.loads((catalog.ROOT / entry["file"]).read_text())
+    del config["architecture"]
+    path = tmp_path / entry["file"]
+    path.parent.mkdir(parents=True)
+    path.write_text(json.dumps(config))
+    cell = next(w["name"] for w in BENCH["workloads"]
+                if w["config"] == entry["name"])
+    with pytest.raises(ValueError, match="architecture") as e:
+        catalog.find_cell(cell, root=tmp_path)
+    assert str(path) in str(e.value)
 
 
 @pytest.mark.parametrize("path", sorted(

@@ -107,8 +107,8 @@ def test_metric_readers_on_the_recorded_trace(trace):
         1e3 * sum(runs) / len(runs))
     assert read("idle_share.chat") == pytest.approx(
         100 * (1 - trace.busy_s() / trace.window_s))
-    flops, nbytes = work.paged_attention_call(QWEN, contexts)
-    least = len(steps) * 28 * max(flops / 197e12, nbytes / 819e9)
+    flops, nbytes = work.paged_attention_call(QWEN, contexts)  # 28 layers
+    least = len(steps) * max(flops / 197e12, nbytes / 819e9)
     kernel = tracing.kernel_seconds(trace, tracing.DECODE_PROGRAM,
                                     tracing.PAGED_ATTENTION_KERNEL)
     share = read("paged_attention_roofline.chat")
